@@ -15,7 +15,9 @@ import json
 import time
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
+    """Run the tables; a failing table is reported as an ERROR row and the
+    rest still run, but the exit code is then 1."""
     from benchmarks import paper_tables, roofline_table
 
     p = argparse.ArgumentParser()
@@ -34,6 +36,7 @@ def main(argv=None) -> None:
                  if any(tok in n for tok in args.only.split(","))]
 
     all_rows = []
+    failed = []
 
     def emit(tag, val, derived):
         all_rows.append({"name": tag, "value": val, "derived": derived})
@@ -45,8 +48,9 @@ def main(argv=None) -> None:
         t0 = time.time()
         try:
             rows = fn(fast=not args.full)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # keep the harness running, fail at the end
             emit(name, "ERROR", f"{type(e).__name__}: {e}")
+            failed.append(name)
             continue
         for tag, val, derived in rows:
             emit(tag, val, derived)
@@ -60,13 +64,17 @@ def main(argv=None) -> None:
                 emit(tag, val, derived)
         except Exception as e:
             emit("roofline", "ERROR", str(e))
+            failed.append("roofline")
 
     if args.json:
         with open(args.json, "w") as f:
             json.dump({"rows": all_rows, "runs": paper_tables.RUN_LOG}, f,
                       indent=2)
         print(f"wrote {args.json} ({len(paper_tables.RUN_LOG)} runs)")
+    if failed:
+        print(f"FAILED: {', '.join(failed)}")
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

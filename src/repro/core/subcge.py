@@ -244,14 +244,15 @@ def scatter_A(i: jax.Array, j: jax.Array, coefs: jax.Array,
 
 
 def apply_A(leaf: jax.Array, uv: UV, A: jax.Array,
-            backend: str | None = None) -> jax.Array:
+            backend: str | None = None, spec=None) -> jax.Array:
     """leaf + U A V^T (batched over instance dims), via the kernel layer.
 
     ``backend=None`` resolves the process default (jnp off-TPU — bitwise the
     historical einsum); callers holding a :class:`SubCGEConfig` pass
-    ``cfg.kernel_backend`` so the knob is captured at trace time.
+    ``cfg.kernel_backend`` so the knob is captured at trace time.  ``spec``
+    is the leaf's PartitionSpec on a sharded mesh (see ``kernels.ops``).
     """
-    return kops.subcge_apply(leaf, uv.U, A, uv.V, backend=backend)
+    return kops.subcge_apply(leaf, uv.U, A, uv.V, backend=backend, spec=spec)
 
 
 def delta_from_A(uv: UV, A: jax.Array, dtype,
@@ -261,7 +262,7 @@ def delta_from_A(uv: UV, A: jax.Array, dtype,
 
 def apply_messages(params: Any, meta: dict[str, LeafMeta], cfg: SubCGEConfig,
                    subspace: dict[str, UV], message_seeds: jax.Array,
-                   coefs: jax.Array) -> Any:
+                   coefs: jax.Array, specs: dict[str, Any] | None = None) -> Any:
     """Apply K seed-scalar messages at once (Algorithm 1 block (C) inner
     update, vectorized).  ``message_seeds``: (K,) uint32; ``coefs``: (K,)
     already carrying the -η·α/n sign/scale convention of the caller.
@@ -269,6 +270,7 @@ def apply_messages(params: Any, meta: dict[str, LeafMeta], cfg: SubCGEConfig,
     Matrix leaves: one scatter + one batched U A V^T per leaf — O(K + r·d),
     dispatched through the kernel layer per ``cfg.kernel_backend``.
     Vector leaves: Σ_k coef_k · N(seed_k) via a scan (memory-light).
+    ``specs`` maps a path to its leaf's PartitionSpec on a sharded mesh.
     """
     backend = cfg.backend()
     coords_k = jax.vmap(lambda s: sample_coords(meta, cfg, s))(message_seeds)
@@ -280,7 +282,8 @@ def apply_messages(params: Any, meta: dict[str, LeafMeta], cfg: SubCGEConfig,
         if m.is_matrix:
             ij = coords_k[path]
             A = scatter_A(ij.i, ij.j, coefs.astype(jnp.float32), cfg.rank)
-            return apply_A(leaf, subspace[path], A, backend)
+            return apply_A(leaf, subspace[path], A, backend,
+                           (specs or {}).get(path))
 
         def body(acc, sc):
             s, c = sc
@@ -447,22 +450,25 @@ def accumulate_buffers(buffers: dict[str, jax.Array], meta, cfg: SubCGEConfig,
 
 def fold_buffers(params: Any, meta, subspace: dict[str, UV],
                  buffers: dict[str, jax.Array],
-                 backend: str | None = None) -> Any:
+                 backend: str | None = None,
+                 specs: dict[str, Any] | None = None) -> Any:
     """Fold W <- W + U A V^T and conceptually reset A (caller zeroes it).
     Must be called before any subspace refresh (the buffer is only valid
     against the U/V it was accumulated under)."""
     def visit(path: str, leaf: jax.Array):
         if path in buffers:
-            return apply_A(leaf, subspace[path], buffers[path], backend)
+            return apply_A(leaf, subspace[path], buffers[path], backend,
+                           (specs or {}).get(path))
         return leaf
     return seedlib.map_with_paths(visit, params)
 
 
 def effective_params(params: Any, meta, subspace, buffers,
-                     backend: str | None = None) -> Any:
+                     backend: str | None = None,
+                     specs: dict[str, Any] | None = None) -> Any:
     """Buffer-mode effective weights W + U A V^T (computed on the fly in the
     forward pass, as the paper's GPU implementation does)."""
-    return fold_buffers(params, meta, subspace, buffers, backend)
+    return fold_buffers(params, meta, subspace, buffers, backend, specs)
 
 
 # ---------------------------------------------------------------------------

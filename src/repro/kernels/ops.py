@@ -21,7 +21,9 @@ plugins, ``PodConfig``) threads the knob explicitly through fresh per-run jit
 closures, so two runs in one process can never share a stale trace.
 
 The kernel modules are imported lazily inside the dispatchers (they import
-:func:`_tile` from here, and the jnp path should not pay for Pallas imports).
+the tiling helpers from here, and the jnp path should not pay for Pallas
+imports).  Under a mesh of more than one device the kernel backends run each
+call per shard (see "sharded meshes" below).
 """
 from __future__ import annotations
 
@@ -29,6 +31,8 @@ import contextlib
 import functools
 
 import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import KERNEL_BACKENDS
 from repro.kernels import ref as _ref
@@ -89,40 +93,104 @@ def resolve_backend(backend: str | None = None) -> str:
 # ---------------------------------------------------------------------------
 # tiling
 # ---------------------------------------------------------------------------
+#
+# Mosaic accepts a block only if each of its last two dims is the whole
+# array dim or aligned to the vreg tile (a multiple of 8 on the sublane, 128
+# on the lane axis).  Every block dim the kernels choose is therefore either
+# the whole dim or a multiple of 128, which satisfies both axes.
 
 def _tile(dim: int, target: int) -> int:
-    """Largest divisor of ``dim`` that is ≤ ``target``, preferring
-    lane-aligned (multiple-of-128) divisors.
+    """Block size for an output (non-contracted) dim, used with a
+    ``pl.cdiv`` grid.
 
-    Among all admissible divisors a multiple of 128 wins even when a larger
-    unaligned divisor exists (MXU/VPU lanes are 128 wide); with no aligned
-    divisor the genuinely largest one is returned — e.g.
-    ``_tile(320, 256) == 160`` (not 80), ``_tile(896, 256) == 128`` (128
-    divides 896; the larger 224 does not align).
+    The whole ``dim`` when it fits in ``target`` (or in one 128 lane tile);
+    otherwise the largest multiple of 128 ≤ ``target`` that divides ``dim``;
+    otherwise the largest multiple of 128 ≤ ``target``, and the last grid
+    block is a partial edge block — e.g. ``_tile(1016, 256) == 256`` (four
+    blocks, the last holding 248 rows) and ``_tile(50272, 256) == 256``.
     """
-    best, best_aligned = 1, 0
-    for t in range(1, min(target, dim) + 1):
+    top = max(128, target - target % 128)
+    if dim <= max(target, top):
+        return dim
+    for t in range(top, 0, -128):
         if dim % t == 0:
-            best = t
-            if t % 128 == 0:
-                best_aligned = t
-    return best_aligned or best
+            return t
+    return top
+
+
+def _tile_k(dim: int, target: int) -> int:
+    """Block size for a contracted dim, which must tile exactly (an edge
+    block would sum its padding into the result): the whole ``dim`` when it
+    fits in ``target``, else the largest multiple-of-128 divisor ≤
+    ``target``, else the whole ``dim``."""
+    if dim <= target:
+        return dim
+    for t in range(target - target % 128, 0, -128):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+# ---------------------------------------------------------------------------
+# sharded meshes
+# ---------------------------------------------------------------------------
+#
+# XLA cannot partition a Mosaic kernel, so under a mesh of more than one
+# device each kernel call runs inside ``jax.shard_map`` on the shards of its
+# operands.  ``spec`` is the PartitionSpec of the weight operand
+# (``models.params.tree_specs``; None = replicated).  A weight sharded on
+# its output axis needs no collective; one sharded on its contracted axis
+# gives per-shard partials x_k W_k + s·(x_k u_k) vᵀ, whose f32 psum is exact
+# because the rank-1 term is linear in the shard too.
+
+def kernel_mesh():
+    """The abstract mesh kernel calls are traced under, or None when one
+    device holds every operand (no mesh set, or a 1-device mesh)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
+
+
+def _axes(spec, ndim: int) -> tuple:
+    """Mesh-axis entries of a weight's PartitionSpec, padded to ``ndim``."""
+    parts = tuple(spec) if spec is not None else ()
+    return parts + (None,) * (ndim - len(parts))
+
+
+def _on_mesh(fn, args, in_specs, out_spec, psum_axis=None):
+    """``fn(*args)``, per shard under a multi-device mesh.  With
+    ``psum_axis`` (a contracted axis is sharded) ``fn`` takes ``out_dtype``:
+    the partials stay f32 through the psum, then cast to ``args[0]``'s."""
+    mesh = kernel_mesh()
+    if mesh is None:
+        return fn(*args)
+    body = fn
+    if psum_axis is not None:
+        def body(*a):
+            y = jax.lax.psum(fn(*a, out_dtype=jnp.float32), psum_axis)
+            return y.astype(a[0].dtype)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_spec, check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
 # dispatchers
 # ---------------------------------------------------------------------------
 
-def subcge_apply(W, U, A, V, *, backend: str | None = None):
+def subcge_apply(W, U, A, V, *, backend: str | None = None, spec=None):
     """W (*B,n,m) + U (n,r) A (*B,r,r) V (m,r)^T — the SubCGE replay."""
     b = resolve_backend(backend)
     if b == "jnp":
         return _ref.subcge_apply(W, U, A, V)
     from repro.kernels import subcge_apply as _apply
-    return _apply.subcge_apply(W, U, A, V, interpret=(b == "interpret"))
+    *bs, ns, ms = _axes(spec, W.ndim)
+    return _on_mesh(
+        functools.partial(_apply.subcge_apply, interpret=(b == "interpret")),
+        (W, U, A, V),
+        (P(*bs, ns, ms), P(ns, None), P(*bs, None, None), P(ms, None)),
+        P(*bs, ns, ms))
 
 
-def subcge_apply_epochs(W, U, A, V, *, backend: str | None = None):
+def subcge_apply_epochs(W, U, A, V, *, backend: str | None = None, spec=None):
     """W (*B,n,m) + Σ_e U (E,n,r)[e] A (E,*B,r,r)[e] V (E,m,r)[e]^T — the
     epoch-grouped padded replay layout (one fused visit of W for all τ-epochs
     present in a flood payload batch)."""
@@ -130,7 +198,14 @@ def subcge_apply_epochs(W, U, A, V, *, backend: str | None = None):
     if b == "jnp":
         return _ref.subcge_apply_epochs(W, U, A, V)
     from repro.kernels import subcge_apply as _apply
-    return _apply.subcge_apply_epochs(W, U, A, V, interpret=(b == "interpret"))
+    *bs, ns, ms = _axes(spec, W.ndim)
+    return _on_mesh(
+        functools.partial(_apply.subcge_apply_epochs,
+                          interpret=(b == "interpret")),
+        (W, U, A, V),
+        (P(*bs, ns, ms), P(None, ns, None), P(None, *bs, None, None),
+         P(None, ms, None)),
+        P(*bs, ns, ms))
 
 
 def subcge_delta(U, A, V, dtype, *, backend: str | None = None):
@@ -140,38 +215,53 @@ def subcge_delta(U, A, V, dtype, *, backend: str | None = None):
     b = resolve_backend(backend)
     if b == "jnp":
         return _ref.subcge_delta(U, A, V, dtype)
-    import jax.numpy as jnp
-    from repro.kernels import subcge_apply as _apply
     zero = jnp.zeros(A.shape[:-2] + (U.shape[-2], V.shape[-2]), dtype)
-    return _apply.subcge_apply(zero, U, A, V, interpret=(b == "interpret"))
+    return subcge_apply(zero, U, A, V, backend=b)
 
 
-def rank1_matmul(x, W, u, v, s, *, backend: str | None = None):
+def rank1_matmul(x, W, u, v, s, *, backend: str | None = None, spec=None):
     """x (M,K) @ (W (K,N) + s·u v^T) — the fused ZO dual forward matmul."""
     b = resolve_backend(backend)
     if b == "jnp":
         return _ref.rank1_matmul(x, W, u, v, s)
     from repro.kernels import rank1_matmul as _r1
-    return _r1.rank1_matmul(x, W, u, v, s, interpret=(b == "interpret"))
+    ks, ns = _axes(spec, 2)
+    return _on_mesh(
+        functools.partial(_r1.rank1_matmul, interpret=(b == "interpret")),
+        (x, W, u, v, s),
+        (P(None, ks), P(ks, ns), P(ks), P(ns), P()), P(None, ns),
+        psum_axis=ks)
 
 
-def rank1_matmul_t(x, W, u, v, s, *, backend: str | None = None):
+def rank1_matmul_t(x, W, u, v, s, *, backend: str | None = None, spec=None):
     """x (M,N) @ (W (O,N) + s·u v^T)^T — tied-embedding logits."""
     b = resolve_backend(backend)
     if b == "jnp":
         return _ref.rank1_matmul_t(x, W, u, v, s)
     from repro.kernels import rank1_matmul as _r1
-    return _r1.rank1_matmul_t(x, W, u, v, s, interpret=(b == "interpret"))
+    os_, ns = _axes(spec, 2)
+    return _on_mesh(
+        functools.partial(_r1.rank1_matmul_t, interpret=(b == "interpret")),
+        (x, W, u, v, s),
+        (P(None, ns), P(os_, ns), P(os_), P(ns), P()), P(None, os_),
+        psum_axis=ns)
 
 
-def rank1_matmul_expert(x, W, u, v, s, *, backend: str | None = None):
+def rank1_matmul_expert(x, W, u, v, s, *, backend: str | None = None,
+                        spec=None):
     """x (E,C,n) @ (W (E,n,m) + s·u[:,e] v[:,e]^T) — per-expert rank-1
     perturbations, u (n,E), v (m,E)."""
     b = resolve_backend(backend)
     if b == "jnp":
         return _ref.rank1_matmul_expert(x, W, u, v, s)
     from repro.kernels import rank1_matmul as _r1
-    return _r1.rank1_matmul_expert(x, W, u, v, s, interpret=(b == "interpret"))
+    es, ks, ms = _axes(spec, 3)
+    return _on_mesh(
+        functools.partial(_r1.rank1_matmul_expert,
+                          interpret=(b == "interpret")),
+        (x, W, u, v, s),
+        (P(es, None, ks), P(es, ks, ms), P(ks, es), P(ms, es), P()),
+        P(es, None, ms), psum_axis=ks)
 
 
 def selective_scan(a, bx, c, h0, *, backend: str | None = None):

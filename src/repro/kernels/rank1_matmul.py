@@ -8,8 +8,11 @@ s·(xu)·v to the accumulator on the final k step.  W is streamed exactly once,
 same as an unperturbed matmul — the perturbation is compute-free at the
 memory roofline.
 
-Grid: (M/bm, N/bn, K/bk), k innermost/sequential; f32 accumulators in VMEM
-scratch (acc for xW, xu for the rank-1 partial).
+Grid: (⌈M/bm⌉, ⌈N/bn⌉, K/bk), k innermost/sequential; f32 accumulators in
+VMEM scratch (acc for xW, xu for the rank-1 partial).  Output dims may end
+in a partial edge block (its padding rows/columns are never written back);
+the contracted K is tiled by an exact divisor or taken whole
+(``ops._tile`` / ``ops._tile_k``).
 """
 from __future__ import annotations
 
@@ -20,11 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import _tile
-
-# jax < 0.5 ships this as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.ops import _tile, _tile_k
 
 
 def _kernel(x_ref, w_ref, u_ref, v_ref, s_ref, o_ref, acc_ref, xu_ref, *, nk):
@@ -46,19 +45,20 @@ def _kernel(x_ref, w_ref, u_ref, v_ref, s_ref, o_ref, acc_ref, xu_ref, *, nk):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bn", "bk", "interpret"))
+                   static_argnames=("bm", "bn", "bk", "interpret", "out_dtype"))
 def rank1_matmul(x: jax.Array, W: jax.Array, u: jax.Array, v: jax.Array,
                  s, *, bm: int = 256, bn: int = 256, bk: int = 512,
-                 interpret: bool = False) -> jax.Array:
-    """x (M,K) @ (W (K,N) + s·u (K,) v (N,)^T) -> (M,N)."""
+                 interpret: bool = False, out_dtype=None) -> jax.Array:
+    """x (M,K) @ (W (K,N) + s·u (K,) v (N,)^T) -> (M,N) in ``out_dtype``
+    (default x.dtype)."""
     M, K = x.shape
     K2, N = W.shape
     assert K == K2 and u.shape == (K,) and v.shape == (N,)
     bm = _tile(M, bm)
     bn = _tile(N, bn)
-    bk = _tile(K, bk)
+    bk = _tile_k(K, bk)
     nk = K // bk
-    grid = (M // bm, N // bn, nk)
+    grid = (pl.cdiv(M, bm), pl.cdiv(N, bn), nk)
 
     out = pl.pallas_call(
         functools.partial(_kernel, nk=nk),
@@ -71,10 +71,10 @@ def rank1_matmul(x: jax.Array, W: jax.Array, u: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),         # s
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, W, u.reshape(K, 1), v.reshape(1, N),
@@ -105,10 +105,10 @@ def _kernel_t(x_ref, w_ref, v_ref, u_ref, s_ref, o_ref, acc_ref, xv_ref, *, nk):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bm", "bo", "bk", "interpret"))
+                   static_argnames=("bm", "bo", "bk", "interpret", "out_dtype"))
 def rank1_matmul_t(x: jax.Array, W: jax.Array, u: jax.Array, v: jax.Array,
                    s, *, bm: int = 256, bo: int = 256, bk: int = 512,
-                   interpret: bool = False) -> jax.Array:
+                   interpret: bool = False, out_dtype=None) -> jax.Array:
     """x (M,N) @ (W (O,N) + s·u (O,) v (N,)^T)^T -> (M,O).
 
     The tied-embedding logits matmul: W is stored output-major (vocab, d) and
@@ -121,9 +121,9 @@ def rank1_matmul_t(x: jax.Array, W: jax.Array, u: jax.Array, v: jax.Array,
     assert N == N2 and u.shape == (O,) and v.shape == (N,)
     bm = _tile(M, bm)
     bo = _tile(O, bo)
-    bk = _tile(N, bk)
+    bk = _tile_k(N, bk)
     nk = N // bk
-    grid = (M // bm, O // bo, nk)
+    grid = (pl.cdiv(M, bm), pl.cdiv(O, bo), nk)
 
     out = pl.pallas_call(
         functools.partial(_kernel_t, nk=nk),
@@ -136,10 +136,10 @@ def rank1_matmul_t(x: jax.Array, W: jax.Array, u: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1), lambda i, j, k: (0, 0)),         # s
         ],
         out_specs=pl.BlockSpec((bm, bo), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((M, O), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, O), out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bo), jnp.float32),
                         pltpu.VMEM((bm, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, W, v.reshape(N, 1), u.reshape(1, O),
@@ -156,37 +156,45 @@ def _kernel_expert(x_ref, w_ref, u_ref, v_ref, s_ref, o_ref, acc_ref, xu_ref,
 
     x = x_ref[0]
     acc_ref[...] += jnp.dot(x, w_ref[0], preferred_element_type=jnp.float32)
-    xu_ref[...] += jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
+    # u row (1, bk) contracted against x's bk axis -> (bc, 1), in the
+    # operands' common dtype (as jnp.dot promotes in the dense kernel)
+    u = u_ref[0]
+    dt = jnp.promote_types(x.dtype, u.dtype)
+    xu_ref[...] += jax.lax.dot_general(
+        x.astype(dt), u.astype(dt), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     @pl.when(pl.program_id(3) == nk - 1)
     def _done():
         s = s_ref[0, 0]
         o_ref[0] = (acc_ref[...]
-                    + s * xu_ref[...] * v_ref[...].astype(jnp.float32).T
+                    + s * xu_ref[...] * v_ref[0].astype(jnp.float32)
                     ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("bc", "bn", "bk", "interpret"))
+                   static_argnames=("bc", "bn", "bk", "interpret", "out_dtype"))
 def rank1_matmul_expert(x: jax.Array, W: jax.Array, u: jax.Array,
                         v: jax.Array, s, *, bc: int = 256, bn: int = 256,
-                        bk: int = 512, interpret: bool = False) -> jax.Array:
+                        bk: int = 512, interpret: bool = False,
+                        out_dtype=None) -> jax.Array:
     """Batched per-expert rank-1-perturbed matmul:
     x (E,C,n), W (E,n,m), u (n,E), v (m,E) ->
     y[e] = x[e] @ W[e] + s·(x[e]·u[:,e]) v[:,e]^T.
 
     Experts ride the leading (parallel) grid axis like the instance dim of
-    ``subcge_apply``; each expert's u/v columns are sliced straight out of
-    the (dim, E) coordinate panels, and the k-loop epilogue is per-expert.
+    ``subcge_apply``; the (dim, E) coordinate panels are laid out expert-major
+    as (E, 1, dim) rows, so each expert's u/v block is a lane-dense (1, b)
+    row, and the k-loop epilogue is per-expert.
     """
     E, C, n = x.shape
     E2, n2, m = W.shape
     assert E == E2 and n == n2 and u.shape == (n, E) and v.shape == (m, E)
     bc = _tile(C, bc)
     bn = _tile(m, bn)
-    bk = _tile(n, bk)
+    bk = _tile_k(n, bk)
     nk = n // bk
-    grid = (E, C // bc, m // bn, nk)
+    grid = (E, pl.cdiv(C, bc), pl.cdiv(m, bn), nk)
 
     out = pl.pallas_call(
         functools.partial(_kernel_expert, nk=nk),
@@ -194,17 +202,18 @@ def rank1_matmul_expert(x: jax.Array, W: jax.Array, u: jax.Array,
         in_specs=[
             pl.BlockSpec((1, bc, bk), lambda e, i, j, k: (e, i, k)),   # x
             pl.BlockSpec((1, bk, bn), lambda e, i, j, k: (e, k, j)),   # W
-            pl.BlockSpec((bk, 1), lambda e, i, j, k: (k, e)),          # u col
-            pl.BlockSpec((bn, 1), lambda e, i, j, k: (j, e)),          # v col
+            pl.BlockSpec((1, 1, bk), lambda e, i, j, k: (e, 0, k)),    # u row
+            pl.BlockSpec((1, 1, bn), lambda e, i, j, k: (e, 0, j)),    # v row
             pl.BlockSpec((1, 1), lambda e, i, j, k: (0, 0)),           # s
         ],
         out_specs=pl.BlockSpec((1, bc, bn), lambda e, i, j, k: (e, i, j)),
-        out_shape=jax.ShapeDtypeStruct((E, C, m), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((E, C, m), out_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bn), jnp.float32),
                         pltpu.VMEM((bc, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(x, W, u, v, jnp.asarray(s, jnp.float32).reshape(1, 1))
+    )(x, W, u.T.reshape(E, 1, n), v.T.reshape(E, 1, m),
+      jnp.asarray(s, jnp.float32).reshape(1, 1))
     return out

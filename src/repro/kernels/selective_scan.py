@@ -21,11 +21,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import _tile
-
-# jax < 0.5 ships this as TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.ops import _tile, _tile_k
 
 
 def _kernel(a_ref, b_ref, c_ref, h0_ref, y_ref, hout_ref, h_ref, *, bt, nt):
@@ -57,9 +53,9 @@ def selective_scan(a: jax.Array, bx: jax.Array, c: jax.Array, h0: jax.Array,
     -> y (B,T,D) f32, h_last (B,D,N) f32."""
     B, T, D, N = a.shape
     bd = _tile(D, bd)
-    bt = _tile(T, bt)
+    bt = _tile_k(T, bt)         # the recurrence runs over T: no partial blocks
     nt = T // bt
-    grid = (B, D // bd, nt)
+    grid = (B, pl.cdiv(D, bd), nt)
 
     # layout: time-major blocks of (bt, bd, N)
     am = jnp.moveaxis(a, 1, 1)  # already (B,T,D,N)
@@ -80,7 +76,7 @@ def selective_scan(a: jax.Array, bx: jax.Array, c: jax.Array, h0: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((B, T, D), jnp.float32),
                    jax.ShapeDtypeStruct((B, D, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bd, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(am, bx, c.reshape(B, T, 1, N), h0)

@@ -8,10 +8,12 @@ visit — arithmetic intensity per W-tile is 2·r·(bn+bm) FLOPs at (bn·bm)
 bytes, so the kernel is HBM-bandwidth-bound at precisely 1× W traffic, the
 roofline floor for any update touching all of W.
 
-Grid: (instances, n/bn, m/bm); instance dims (scan periods, experts) are
-collapsed into the leading grid axis.  A (r×r per instance) and the U/V
-column panels ride along in VMEM; MXU-aligned tiles (multiples of
-128 where the weight allows).
+Grid: (instances, ⌈n/bn⌉, ⌈m/bm⌉); instance dims (scan periods, experts)
+are collapsed into the leading grid axis.  A (r×r per instance) and the U/V
+column panels ride along in VMEM; tiles are the whole dim or multiples of
+128, with a partial edge block where no aligned tile divides the weight
+(``ops._tile``) — every output element depends only on its own row and
+column, so the edge padding never reaches a written element.
 """
 from __future__ import annotations
 
@@ -48,7 +50,7 @@ def subcge_apply(W: jax.Array, U: jax.Array, A: jax.Array, V: jax.Array,
 
     bn = _tile(n, bn)
     bm = _tile(m, bm)
-    grid = (nb, n // bn, m // bm)
+    grid = (nb, pl.cdiv(n, bn), pl.cdiv(m, bm))
 
     out = pl.pallas_call(
         _kernel,

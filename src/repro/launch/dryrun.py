@@ -1,9 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS", ""))
+os.environ["JAX_PLATFORMS"] = "cpu"   # a CPU lowering tool: never take a chip
 """Multi-pod dry-run: prove every (arch × shape × mesh) lowers + compiles.
 
-MUST be the process entry point (the XLA_FLAGS line above runs before any
+MUST be the process entry point (the environment lines above run before any
 jax import, including transitively through repro).  Usage:
 
     PYTHONPATH=src python -m repro.launch.dryrun \
@@ -94,7 +95,7 @@ def run_dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
     resident = sum(_per_device(a, s) for a, s in zip(example, in_sh))
 
     t0 = time.time()   # lower/compile timing report only; never seeds anything
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
         lowered = jitted.lower(*example)
         t_lower = time.time() - t0

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.configs import archs
 from repro.launch import steps as steplib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import params as plib
 from repro.models import transformer as tf
@@ -43,6 +44,7 @@ def main(argv=None) -> int:
     p.add_argument("--sampling", choices=SAMPLING_KINDS, default="greedy")
     p.add_argument("--temperature", type=float, default=0.8)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     cfg = archs.get(args.arch)
     if args.reduced:

@@ -165,9 +165,17 @@ def build_seedflood_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
 
     params_abs = plib.abstract_params(spec, pod.param_dtype)
     params_sh = plib.tree_shardings(spec, mesh, cfg.sharding_policy)
+    leaf_specs = plib.flatten_paths(
+        plib.tree_specs(spec, mesh, cfg.sharding_policy))
     batch_abs, batch_sh = train_inputs(cfg, shape, mesh, pod)
 
     def train_step(params, batch, step):
+        # the step traces under its own mesh, so the kernels in it run as
+        # per-shard shard_map calls whatever mesh context the caller holds
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return _step(params, batch, step)
+
+    def _step(params, batch, step):
         # buffer mode (paper App. A): params = (base W, A-buffers); the
         # effective weights W + U A V^T are materialized on the fly each
         # step and A is folded into W at subspace-refresh boundaries (a
@@ -182,14 +190,16 @@ def build_seedflood_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
             params = jax.tree.map(
                 lambda base, folded: jnp.where(is_refresh, folded, base),
                 params, subcge.fold_buffers(params, meta, old_sub, bufs,
-                                            backend=pod.kernel_backend))
+                                            backend=pod.kernel_backend,
+                                            specs=leaf_specs))
             bufs = jax.tree.map(
                 lambda b: jnp.where(is_refresh, jnp.zeros_like(b), b), bufs)
 
         sub_flat = subcge.subspace_at_step(meta, scfg, pod.base_seed, step)
         sub = nest_subspace(sub_flat)
         eff = (subcge.effective_params(params, meta, sub_flat, bufs,
-                                       backend=pod.kernel_backend)
+                                       backend=pod.kernel_backend,
+                                       specs=leaf_specs)
                if buffer_mode else params)
         cids = jnp.arange(n)
         seeds_t = jax.vmap(lambda i: seedlib.client_seed(pod.base_seed, step, i))(cids)
@@ -227,7 +237,7 @@ def build_seedflood_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
                                                   seeds_t, coefs)
             return (params, bufs), metrics
         new_params = subcge.apply_messages(params, meta, scfg, sub_flat,
-                                           seeds_t, coefs)
+                                           seeds_t, coefs, leaf_specs)
         return new_params, metrics
 
     if pod.apply_mode == "buffer":

@@ -172,14 +172,27 @@ def spec_partition(axes: tuple[str | None, ...], rules: dict[str, str],
     return P(*parts)
 
 
-def leaf_sharding(spec: LeafSpec, mesh: Mesh, rules: dict[str, str]) -> NamedSharding:
+def leaf_spec(spec: LeafSpec, mesh, rules: dict[str, str]) -> P:
+    """PartitionSpec of one leaf on ``mesh`` (a ``Mesh`` or the
+    ``AbstractMesh`` a trace runs under)."""
     parts = list(spec_partition(spec.axes, rules, mesh))
     # drop assignments that don't divide the dimension
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    sizes = dict(mesh.shape)
     for d, m in enumerate(parts):
         if m is not None and spec.shape[d] % sizes[m] != 0:
             parts[d] = None
-    return NamedSharding(mesh, P(*parts))
+    return P(*parts)
+
+
+def leaf_sharding(spec: LeafSpec, mesh: Mesh, rules: dict[str, str]) -> NamedSharding:
+    return NamedSharding(mesh, leaf_spec(spec, mesh, rules))
+
+
+def tree_specs(specs: Any, mesh, policy: str) -> Any:
+    """PartitionSpec per leaf — what :func:`tree_shardings` places, for code
+    that needs the layout inside a trace (``kernels.ops`` under a mesh)."""
+    rules = POLICIES[policy]
+    return jax.tree.map(lambda s: leaf_spec(s, mesh, rules), specs)
 
 
 def tree_shardings(specs: Any, mesh: Mesh, policy: str) -> Any:

@@ -22,6 +22,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import seeds as seedlib
 from repro.core import subcge
@@ -80,16 +81,9 @@ def _child(tree: Any, k: str):
 
 
 def _mesh_active() -> bool:
-    """True when a mesh context is available for sharding constraints
+    """True when a mesh is set (``jax.set_mesh``) for sharding constraints
     (simulator / CPU smoke paths run mesh-less and skip them)."""
-    try:
-        from jax._src import mesh as _mesh_lib
-        if not _mesh_lib.thread_resources.env.physical_mesh.empty:
-            return True
-        am = _mesh_lib.get_abstract_mesh()
-        return am is not None and not am.empty
-    except Exception:  # pragma: no cover - jax internals moved
-        return False
+    return not jax.sharding.get_abstract_mesh().empty
 
 
 class Bundle:
@@ -100,16 +94,21 @@ class Bundle:
     string fixed at trace time, threaded from ``forward(kernel_backend=…)``.
     The unperturbed forward (serving, FO baselines, eval) never dispatches:
     it is a plain matmul with nothing to fuse.
-    """
-    __slots__ = ("p", "uv", "ij", "zv", "scale", "kb")
 
-    def __init__(self, p, uv=None, ij=None, zv=None, scale=None, kb="jnp"):
+    ``sp`` mirrors the params with each leaf's PartitionSpec when the kernels
+    run under a multi-device mesh (``models.params.tree_specs``), else None.
+    """
+    __slots__ = ("p", "uv", "ij", "zv", "scale", "kb", "sp")
+
+    def __init__(self, p, uv=None, ij=None, zv=None, scale=None, kb="jnp",
+                 sp=None):
         self.p = p
         self.uv = uv
         self.ij = ij
         self.zv = zv
         self.scale = scale
         self.kb = kb
+        self.sp = sp
 
     @classmethod
     def make(cls, params, subspace_nested=None, pert: Pert | None = None,
@@ -121,7 +120,14 @@ class Bundle:
 
     def __getitem__(self, k: str) -> "Bundle":
         return Bundle(self.p[k], _child(self.uv, k), _child(self.ij, k),
-                      _child(self.zv, k), self.scale, self.kb)
+                      _child(self.zv, k), self.scale, self.kb,
+                      _child(self.sp, k))
+
+    def _spec(self, k: str, ndim: int):
+        """PartitionSpec of leaf k's trailing ``ndim`` dims (a scanned leaf
+        arrives without its stacked layer dim), or None."""
+        sp = _child(self.sp, k)
+        return None if sp is None else P(*tuple(sp)[-ndim:])
 
     def __contains__(self, k: str) -> bool:
         return k in self.p
@@ -149,7 +155,7 @@ class Bundle:
         if r1 is not None and self.kb != "jnp":
             u, v, s = r1
             y = kops.rank1_matmul(x.reshape(-1, x.shape[-1]), W, u, v, s,
-                                  backend=self.kb)
+                                  backend=self.kb, spec=self._spec(k, 2))
             y = y.reshape(x.shape[:-1] + (W.shape[-1],))
         else:
             y = jnp.einsum("...n,nm->...m", x, W)
@@ -170,7 +176,7 @@ class Bundle:
         if r1 is not None and self.kb != "jnp":
             u, v, s = r1
             y = kops.rank1_matmul_t(x.reshape(-1, x.shape[-1]), W, u, v, s,
-                                    backend=self.kb)
+                                    backend=self.kb, spec=self._spec(k, 2))
             return y.reshape(x.shape[:-1] + (W.shape[0],))
         y = jnp.einsum("...n,mn->...m", x, W)
         if r1 is not None:
@@ -234,7 +240,9 @@ class Bundle:
         r1 = self._rank1(k)
         if r1 is not None and self.kb != "jnp":
             u, v, s = r1          # u (n, E), v (m, E)
-            return kops.rank1_matmul_expert(x, W, u, v, s, backend=self.kb)
+            return kops.rank1_matmul_expert(
+                x, W, u, v, s, backend=self.kb,
+                spec=weight_spec or self._spec(k, 3))
         y = jnp.einsum("ecn,enm->ecm", x, W)
         if r1 is not None:
             u, v, s = r1          # u (n, E), v (m, E)

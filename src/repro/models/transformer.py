@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, LayerCfg
+from repro.kernels import ops as kops
 from repro.models import layers as L
 from repro.models import params as plib
 from repro.models.params import LeafSpec, matrix, vector
@@ -316,6 +317,10 @@ def forward(cfg: ArchConfig, params: Any, batch: dict, *,
     """
     paged = paged_table is not None
     root = Bundle.make(params, sub, pert, kernel_backend)
+    mesh = kops.kernel_mesh()
+    if pert is not None and root.kb != "jnp" and mesh is not None:
+        # the perturbed matmuls run as per-shard kernels (kernels.ops)
+        root.sp = plib.tree_specs(arch_spec(cfg), mesh, cfg.sharding_policy)
     be = root["embed"]
     tokens = batch["tokens"]
     x = be.embed("tok", tokens)
@@ -349,17 +354,18 @@ def forward(cfg: ArchConfig, params: Any, batch: dict, *,
         gij = _child(pert.ij, gk) if pert is not None else None
         gzv = _child(pert.zv, gk) if pert is not None else None
         guv = _child(sub, gk)
+        gsp = _child(root.sp, gk)
         gcache = cache[gk] if cache is not None else None
         scale = pert.scale if pert is not None else None
 
-        def body(carry, xs, g=g, guv=guv, scale=scale, kb=root.kb):
+        def body(carry, xs, g=g, guv=guv, gsp=gsp, scale=scale, kb=root.kb):
             xc, aux_c = carry
             pslice, ijslice, zvslice, cslice = xs
             ncs: dict[str, Any] = {}
             for si, slot in enumerate(g.slots):
                 sk = f"s{si}"
                 sb = Bundle(pslice[sk], _child(guv, sk), _child(ijslice, sk),
-                            _child(zvslice, sk), scale, kb)
+                            _child(zvslice, sk), scale, kb, _child(gsp, sk))
                 cslot = cslice[sk] if cslice is not None else None
                 xc, nc, aux = _apply_slot(slot, sb, xc, cslot, pos, cfg,
                                           paged_table=paged_table)

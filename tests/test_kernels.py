@@ -14,9 +14,10 @@ from repro.kernels import ops, ref  # sfcheck: noqa[SF006] -- this suite IS the 
 # ---------------------------------------------------------------------------
 
 def test_tile_320_256_regression():
-    # the historical subcge_apply._tile returned 80 here, skipping the valid
-    # 160 — pinned so the "largest admissible divisor" contract can't rot
-    assert ops._tile(320, 256) == 160
+    # 160 (the largest divisor) is neither a multiple of 128 nor the whole
+    # dim, which the TPU compiler refuses as a block; no aligned divisor
+    # exists, so the tile is 256 with a partial edge block
+    assert ops._tile(320, 256) == 256
 
 
 @pytest.mark.parametrize("dim,target,want", [
@@ -25,31 +26,43 @@ def test_tile_320_256_regression():
     (512, 256, 256),    # aligned divisor at target
     (896, 256, 128),    # 128 divides 896; the larger 224 is unaligned
     (384, 256, 128),    # ditto: 192 is larger but unaligned
-    (320, 256, 160),    # no aligned divisor -> genuinely largest
+    (320, 256, 256),    # no aligned divisor -> 256 with an edge block
+    (1016, 256, 256),   # the pod step's rows per client (8 x 127)
+    (50272, 256, 256),  # OPT's vocabulary = 32 x 1571
     (96, 256, 96),
     (7, 256, 7),
-    (100, 64, 50),
+    (100, 64, 100),     # one lane tile holds the whole dim
     (1, 256, 1),
 ])
 def test_tile_cases(dim, target, want):
     assert ops._tile(dim, target) == want
 
 
-@pytest.mark.parametrize("dim", [1, 7, 96, 100, 320, 512, 896, 1000])
+@pytest.mark.parametrize("dim", [1, 7, 96, 100, 320, 512, 896, 1000, 1016,
+                                 50272])
 @pytest.mark.parametrize("target", [1, 128, 256, 512])
 def test_tile_properties(dim, target):
     t = ops._tile(dim, target)
-    assert 1 <= t <= max(1, min(dim, target))
-    assert dim % t == 0
-    # preference contract: if any multiple-of-128 divisor is admissible, the
-    # result is one of them — and the largest such
-    aligned = [d for d in range(1, min(dim, target) + 1)
-               if dim % d == 0 and d % 128 == 0]
-    if aligned:
+    # chip-legal block: the whole dim, or lane-aligned and inside the dim
+    assert t == dim or (t % 128 == 0 and t < dim)
+    assert t <= max(dim if dim <= 128 else 128, target)
+    # preference contract: when the dim does not fit, an aligned divisor
+    # within target wins, and the largest such
+    aligned = [d for d in range(128, target + 1, 128) if dim % d == 0]
+    if t != dim and aligned:
         assert t == max(aligned)
-    else:
-        assert t == max(d for d in range(1, min(dim, target) + 1)
-                        if dim % d == 0)
+
+
+@pytest.mark.parametrize("dim,target,want", [
+    (2048, 512, 512), (5632, 512, 512), (8192, 512, 512),
+    (896, 512, 128),    # exact aligned divisor
+    (320, 512, 320),    # fits whole
+    (1000, 512, 1000),  # no aligned divisor: the whole contraction
+    (64, 512, 64),
+])
+def test_tile_k_cases(dim, target, want):
+    t = ops._tile_k(dim, target)
+    assert t == want and dim % t == 0
 
 
 def test_tile_shared_by_all_kernel_modules():
@@ -197,6 +210,44 @@ def test_rank1_matmul_kernel(mkn, dtype, s):
     np.testing.assert_allclose(np.asarray(got, jnp.float32),
                                np.asarray(want, jnp.float32),
                                rtol=tol, atol=tol * 20)
+
+
+# shapes the TPU compiler refused before edge blocks: a row count with no
+# aligned divisor (the pod step's 1016 = 8·127 rows, scaled to 504 = 8·63)
+# and an output width with no multiple-of-128 divisor (OPT's vocabulary
+# 50272 = 32·1571, scaled to 416 = 32·13)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("op", ["rank1_matmul", "rank1_matmul_t"])
+def test_rank1_matmul_unaligned_rows_and_width(op, dtype):
+    M, K, N = 504, 256, 416
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(ks[0], (M, K), dtype)
+    wshape, ushape = ((K, N), (K,)) if op == "rank1_matmul" else ((N, K), (N,))
+    W = jax.random.normal(ks[1], wshape, dtype)
+    u = jax.random.normal(ks[2], ushape, jnp.float32)
+    v = jax.random.normal(ks[3], (N,) if op == "rank1_matmul" else (K,),
+                          jnp.float32)
+    got = getattr(ops, op)(x, W, u, v, 0.3, backend="interpret")
+    want = getattr(ref, op)(x, W, u, v, 0.3)
+    assert got.shape == (M, N)
+    tol = 1e-4 if dtype == jnp.float32 else 4e-2
+    np.testing.assert_allclose(np.asarray(got, jnp.float32),
+                               np.asarray(want, jnp.float32),
+                               rtol=tol, atol=tol * 20)
+
+
+@pytest.mark.parametrize("shape", [(416, 504), (2, 504, 416)])
+def test_subcge_apply_unaligned_edge_blocks(shape):
+    n, m = shape[-2:]
+    ks = jax.random.split(jax.random.PRNGKey(8), 4)
+    W = jax.random.normal(ks[0], shape)
+    U = jax.random.normal(ks[1], (n, 8))
+    V = jax.random.normal(ks[2], (m, 8))
+    A = jax.random.normal(ks[3], shape[:-2] + (8, 8))
+    got = ops.subcge_apply(W, U, A, V, backend="interpret")
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(ref.subcge_apply(W, U, A, V)),
+                               rtol=1e-4, atol=1e-3)
 
 
 def test_rank1_matmul_zero_scale_is_plain_matmul():
