@@ -8,6 +8,10 @@ s·(xu)·v to the accumulator on the final k step.  W is streamed exactly once,
 same as an unperturbed matmul — the perturbation is compute-free at the
 memory roofline.
 
+Each call is named after its dispatcher in ``kernels/ops.py``
+(``pallas_call(name=...)``), which is the name its op carries in a compiled
+program and a device profile.
+
 Grid: (⌈M/bm⌉, ⌈N/bn⌉, K/bk), k innermost/sequential; f32 accumulators in
 VMEM scratch (acc for xW, xu for the rank-1 partial).  Output dims may end
 in a partial edge block (its padding rows/columns are never written back);
@@ -62,6 +66,7 @@ def rank1_matmul(x: jax.Array, W: jax.Array, u: jax.Array, v: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_kernel, nk=nk),
+        name="rank1_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),       # x
@@ -127,6 +132,7 @@ def rank1_matmul_t(x: jax.Array, W: jax.Array, u: jax.Array, v: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_kernel_t, nk=nk),
+        name="rank1_matmul_t",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),       # x
@@ -198,6 +204,7 @@ def rank1_matmul_expert(x: jax.Array, W: jax.Array, u: jax.Array,
 
     out = pl.pallas_call(
         functools.partial(_kernel_expert, nk=nk),
+        name="rank1_matmul_expert",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bc, bk), lambda e, i, j, k: (e, i, k)),   # x

@@ -34,11 +34,10 @@ def _kernel(w_ref, u_ref, v_ref, a_ref, o_ref):
     o_ref[0] = (w_ref[0].astype(jnp.float32) + delta).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
-def subcge_apply(W: jax.Array, U: jax.Array, A: jax.Array, V: jax.Array,
-                 *, bn: int = 256, bm: int = 256,
-                 interpret: bool = False) -> jax.Array:
-    """W (*B, n, m) + U (n, r) @ A (*B, r, r) @ V (m, r)^T."""
+def _apply(W, U, A, V, bn: int, bm: int, interpret: bool, name: str):
+    """The kernel call of both entry points, named after the dispatcher in
+    ``kernels/ops.py`` that reaches it: the name its op carries in a
+    compiled program and a device profile."""
     batch = W.shape[:-2]
     n, m = W.shape[-2:]
     r = U.shape[-1]
@@ -54,6 +53,7 @@ def subcge_apply(W: jax.Array, U: jax.Array, A: jax.Array, V: jax.Array,
 
     out = pl.pallas_call(
         _kernel,
+        name=name,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bn, bm), lambda b, i, j: (b, i, j)),
@@ -66,6 +66,14 @@ def subcge_apply(W: jax.Array, U: jax.Array, A: jax.Array, V: jax.Array,
         interpret=interpret,
     )(Wf, U, V, Af)
     return out.reshape(W.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
+def subcge_apply(W: jax.Array, U: jax.Array, A: jax.Array, V: jax.Array,
+                 *, bn: int = 256, bm: int = 256,
+                 interpret: bool = False) -> jax.Array:
+    """W (*B, n, m) + U (n, r) @ A (*B, r, r) @ V (m, r)^T."""
+    return _apply(W, U, A, V, bn, bm, interpret, "subcge_apply")
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bm", "interpret"))
@@ -92,14 +100,14 @@ def subcge_apply_epochs(W: jax.Array, U: jax.Array, A: jax.Array,
     for b in batch:
         nb *= b
     if E == 1:
-        return subcge_apply(W, U[0], A[0], V[0], bn=bn, bm=bm,
-                            interpret=interpret)
+        return _apply(W, U[0], A[0], V[0], bn, bm, interpret,
+                      "subcge_apply_epochs")
     Uc = jnp.moveaxis(U, 0, 1).reshape(n, E * r)
     Vc = jnp.moveaxis(V, 0, 1).reshape(m, E * r)
     Af = A.reshape(E, nb, r, r).astype(jnp.float32)
     blk = jnp.zeros((nb, E * r, E * r), jnp.float32)
     for e in range(E):
         blk = blk.at[:, e * r:(e + 1) * r, e * r:(e + 1) * r].set(Af[e])
-    out = subcge_apply(W.reshape(nb, n, m), Uc, blk, Vc, bn=bn, bm=bm,
-                       interpret=interpret)
+    out = _apply(W.reshape(nb, n, m), Uc, blk, Vc, bn, bm, interpret,
+                 "subcge_apply_epochs")
     return out.reshape(W.shape)

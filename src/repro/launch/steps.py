@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import ArchConfig, InputShape
 from repro.core import seeds as seedlib, subcge
 from repro.core.subcge import SubCGEConfig
@@ -181,37 +182,48 @@ def build_seedflood_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
         # step and A is folded into W at subspace-refresh boundaries (a
         # buffer is only valid under the subspace it accumulated against).
         buffer_mode = pod.apply_mode == "buffer"
+        # phases (obs.scope): "subspace" is the step's draw of U, V and the
+        # client seeds; "ge" (gradient estimation, paper Table 4) every
+        # client's ± perturbed forwards and α; "ma" (message apply) the fold
+        # of every message into the weights
         if buffer_mode:
             params, bufs = params
-            is_refresh = jnp.logical_and(step > 0,
-                                         step % scfg.refresh_period == 0)
-            old_sub = subcge.subspace_at_step(meta, scfg, pod.base_seed,
-                                              jnp.maximum(step - 1, 0))
-            params = jax.tree.map(
-                lambda base, folded: jnp.where(is_refresh, folded, base),
-                params, subcge.fold_buffers(params, meta, old_sub, bufs,
-                                            backend=pod.kernel_backend,
-                                            specs=leaf_specs))
-            bufs = jax.tree.map(
-                lambda b: jnp.where(is_refresh, jnp.zeros_like(b), b), bufs)
+            with obs.scope("ma"):
+                is_refresh = jnp.logical_and(step > 0,
+                                             step % scfg.refresh_period == 0)
+                old_sub = subcge.subspace_at_step(meta, scfg, pod.base_seed,
+                                                  jnp.maximum(step - 1, 0))
+                params = jax.tree.map(
+                    lambda base, folded: jnp.where(is_refresh, folded, base),
+                    params, subcge.fold_buffers(params, meta, old_sub, bufs,
+                                                backend=pod.kernel_backend,
+                                                specs=leaf_specs))
+                bufs = jax.tree.map(
+                    lambda b: jnp.where(is_refresh, jnp.zeros_like(b), b),
+                    bufs)
 
-        sub_flat = subcge.subspace_at_step(meta, scfg, pod.base_seed, step)
-        sub = nest_subspace(sub_flat)
-        eff = (subcge.effective_params(params, meta, sub_flat, bufs,
-                                       backend=pod.kernel_backend,
-                                       specs=leaf_specs)
-               if buffer_mode else params)
-        cids = jnp.arange(n)
-        seeds_t = jax.vmap(lambda i: seedlib.client_seed(pod.base_seed, step, i))(cids)
+        with obs.scope("subspace"):
+            sub_flat = subcge.subspace_at_step(meta, scfg, pod.base_seed,
+                                               step)
+            sub = nest_subspace(sub_flat)
+            cids = jnp.arange(n)
+            seeds_t = jax.vmap(
+                lambda i: seedlib.client_seed(pod.base_seed, step, i))(cids)
+        with obs.scope("ge"):
+            eff = (subcge.effective_params(params, meta, sub_flat, bufs,
+                                           backend=pod.kernel_backend,
+                                           specs=leaf_specs)
+                   if buffer_mode else params)
 
         def client_alpha(batch_i, seed_i):
-            pert = sample_pert(meta, scfg, seed_i, pod.eps)
-            lp = tf.lm_loss(cfg, eff, batch_i, sub=sub, pert=pert,
-                            kernel_backend=pod.kernel_backend)
-            lm = tf.lm_loss(cfg, eff, batch_i, sub=sub,
-                            pert=pert.with_scale(-pod.eps),
-                            kernel_backend=pod.kernel_backend)
-            return (lp - lm) / (2 * pod.eps), 0.5 * (lp + lm)
+            with obs.scope("ge"):
+                pert = sample_pert(meta, scfg, seed_i, pod.eps)
+                lp = tf.lm_loss(cfg, eff, batch_i, sub=sub, pert=pert,
+                                kernel_backend=pod.kernel_backend)
+                lm = tf.lm_loss(cfg, eff, batch_i, sub=sub,
+                                pert=pert.with_scale(-pod.eps),
+                                kernel_backend=pod.kernel_backend)
+                return (lp - lm) / (2 * pod.eps), 0.5 * (lp + lm)
 
         if pod.remat_clients:
             alphas, losses = jax.lax.map(lambda ab: client_alpha(ab[0], ab[1]),
@@ -226,18 +238,20 @@ def build_seedflood_train_step(cfg: ArchConfig, shape: InputShape, mesh: Mesh,
             alphas, losses = jax.vmap(client_alpha)(batch, seeds_t)
 
         # --- consensus: the flood-equivalent all-gather of (seed, α) -------
-        coefs = (-pod.lr / n) * alphas
         metrics = {"loss": jnp.mean(losses),
                    "alpha_rms": jnp.sqrt(jnp.mean(alphas ** 2)),
                    "step": step}
-        if buffer_mode:  # O(n) coordinate updates only (Table 4 "MA" row);
-            # non-matrix leaves follow MeZO directly (App. A)
-            bufs = subcge.accumulate_buffers(bufs, meta, scfg, seeds_t, coefs)
-            params = subcge.apply_vector_messages(params, meta, scfg,
-                                                  seeds_t, coefs)
-            return (params, bufs), metrics
-        new_params = subcge.apply_messages(params, meta, scfg, sub_flat,
-                                           seeds_t, coefs, leaf_specs)
+        with obs.scope("ma"):
+            coefs = (-pod.lr / n) * alphas
+            if buffer_mode:  # O(n) coordinate updates only (Table 4 "MA"
+                # row); non-matrix leaves follow MeZO directly (App. A)
+                bufs = subcge.accumulate_buffers(bufs, meta, scfg, seeds_t,
+                                                 coefs)
+                params = subcge.apply_vector_messages(params, meta, scfg,
+                                                      seeds_t, coefs)
+                return (params, bufs), metrics
+            new_params = subcge.apply_messages(params, meta, scfg, sub_flat,
+                                               seeds_t, coefs, leaf_specs)
         return new_params, metrics
 
     if pod.apply_mode == "buffer":

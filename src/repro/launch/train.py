@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.checkpoint import ckpt
 from repro.configs import archs
 from repro.configs.base import ArchConfig, InputShape
@@ -74,12 +75,15 @@ class PodRun:
 def compile_step(cfg: ArchConfig, shape: InputShape, mesh,
                  pod: steplib.PodConfig):
     """Lower and compile the SeedFlood train step; returns (compiled
-    step, its input shardings, compile seconds)."""
+    step, its input shardings, compile seconds).  The compiled step is
+    registered with :mod:`repro.obs`, so a profile of it can be read by
+    phase."""
     fn, example, in_sh, out_sh = steplib.build_seedflood_train_step(
         cfg, shape, mesh, pod)
     t0 = time.perf_counter()   # set-up timing report only
     compiled = jax.jit(fn, in_shardings=in_sh,
                        out_shardings=out_sh).lower(*example).compile()
+    obs.register(compiled)
     return compiled, in_sh, time.perf_counter() - t0
 
 

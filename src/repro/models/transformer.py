@@ -17,6 +17,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ArchConfig, LayerCfg
 from repro.kernels import ops as kops
 from repro.models import layers as L
@@ -287,14 +288,15 @@ def _apply_slot(slot: LayerCfg, sb: Bundle, x: jax.Array, cache_slot,
         x = x + y
 
     aux = jnp.zeros((), jnp.float32)
-    if slot.ffn == "dense":
-        h = L.norm(sb, "ln_mlp", x, cfg.norm)
-        x = x + L.mlp(sb, h, cfg.act, cfg.gated_mlp)
-    elif slot.ffn == "moe":
-        h = L.norm(sb, "ln_mlp", x, cfg.norm)
-        y, aux = L.moe(sb, h, slot.moe, cfg.act, cfg.gated_mlp,
-                       gather_weights=cfg.moe_gather_weights)
-        x = x + y
+    with obs.scope("mlp"):
+        if slot.ffn == "dense":
+            h = L.norm(sb, "ln_mlp", x, cfg.norm)
+            x = x + L.mlp(sb, h, cfg.act, cfg.gated_mlp)
+        elif slot.ffn == "moe":
+            h = L.norm(sb, "ln_mlp", x, cfg.norm)
+            y, aux = L.moe(sb, h, slot.moe, cfg.act, cfg.gated_mlp,
+                           gather_weights=cfg.moe_gather_weights)
+            x = x + y
     return x, new_cache, aux
 
 
@@ -376,11 +378,12 @@ def forward(cfg: ArchConfig, params: Any, batch: dict, *,
             body, (x, aux_total), (gp, gij, gzv, gcache))
         new_cache[gk] = ncache
 
-    x = L.norm(be, "ln_f", x, cfg.norm)
-    if cfg.tie_embeddings:
-        logits = be.dense_t("tok", x)
-    else:
-        logits = be.dense("out", x)
+    with obs.scope("head"):
+        x = L.norm(be, "ln_f", x, cfg.norm)
+        if cfg.tie_embeddings:
+            logits = be.dense_t("tok", x)
+        else:
+            logits = be.dense("out", x)
     return logits, (new_cache if cache is not None else None), aux_total
 
 
@@ -398,15 +401,18 @@ def lm_loss(cfg: ArchConfig, params: Any, batch: dict, *,
     tokens = batch["tokens"]
     off = logits.shape[1] - tokens.shape[1]          # n frontend embeds
     Tt = tokens.shape[1]
-    lg = logits[:, off: off + Tt - 1].astype(jnp.float32)
-    labels = tokens[:, 1:]
-    lse = jax.scipy.special.logsumexp(lg, axis=-1)
-    # gold logit via masked reduction, NOT take_along_axis: a gather across a
-    # vocab-sharded axis would force an all-gather of the full logits under
-    # SPMD; the select+reduce keeps partial sums shard-local.
-    vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lg.shape, lg.ndim - 1)
-    gold = jnp.sum(jnp.where(vocab_iota == labels[..., None], lg, 0.0), axis=-1)
-    return jnp.mean(lse - gold) + aux
+    with obs.scope("head"):
+        lg = logits[:, off: off + Tt - 1].astype(jnp.float32)
+        labels = tokens[:, 1:]
+        lse = jax.scipy.special.logsumexp(lg, axis=-1)
+        # gold logit via masked reduction, NOT take_along_axis: a gather
+        # across a vocab-sharded axis would force an all-gather of the full
+        # logits under SPMD; the select+reduce keeps partial sums shard-local.
+        vocab_iota = jax.lax.broadcasted_iota(jnp.int32, lg.shape,
+                                              lg.ndim - 1)
+        gold = jnp.sum(jnp.where(vocab_iota == labels[..., None], lg, 0.0),
+                       axis=-1)
+        return jnp.mean(lse - gold) + aux
 
 
 # ---------------------------------------------------------------------------

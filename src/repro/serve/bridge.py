@@ -110,4 +110,5 @@ class LiveUpdateBridge:
 
     def stats(self) -> dict:
         return {"messages_folded": self.messages_folded,
-                "n_folds": self.n_folds, "pending": self.pending}
+                "n_folds": self.n_folds, "pending": self.pending,
+                "fold_programs": len(self._fold_fns)}

@@ -12,7 +12,12 @@ decode-step boundary:
     4. evict  — finished slots free their pages back to the queue
 
 Compiled programs are cached per shape key — (Bg, T) for prefill, bucket
-for decode — so a long-running server converges to a handful of traces.
+for decode — so a long-running server converges to a handful of traces
+(``stats()["programs"]`` counts them).  Each phase of a step runs under a
+host span (``repro.obs.span``: ``server.fold``, ``server.admit``,
+``server.prefill``, ``server.decode``, and ``server.sample`` for the loop
+that turns a dispatch's logits into tokens), so a profile names what the
+host was doing while the device waited.
 No buffer donation anywhere: simulated servers may share a params tree
 (and on CPU donation is a no-op with warnings), and the live-update parity
 oracle compares against the undonated monolithic path.
@@ -23,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import InputShape
 from repro.launch import steps as steplib
 from repro.launch.mesh import make_host_mesh
@@ -113,11 +119,13 @@ class DecodeServer:
             return
         self.n_steps += 1
         if self.bridge is not None and self.bridge.pending:
-            self.params = self.bridge.fold(self.params)
-        admitted = self.sched.admit()
-        groups: dict[int, list[tuple[int, Request]]] = {}
-        for slot, req in admitted:
-            groups.setdefault(len(req.prompt), []).append((slot, req))
+            with obs.span("server.fold"):
+                self.params = self.bridge.fold(self.params)
+        with obs.span("server.admit"):
+            admitted = self.sched.admit()
+            groups: dict[int, list[tuple[int, Request]]] = {}
+            for slot, req in admitted:
+                groups.setdefault(len(req.prompt), []).append((slot, req))
         for T in sorted(groups):
             self._prefill_group(T, groups[T])
         if self.sched.active_slots():
@@ -128,32 +136,35 @@ class DecodeServer:
         tokens = np.stack([r.prompt for _, r in group])
         table = np.stack([self.sched.alloc.table[s] for s, _ in group])
         fn = self._prefill_fn(Bg, T)
-        with self.mesh:
+        with obs.span("server.prefill"), self.mesh:
             last, self.pool = fn(self.params, self.pool,
                                  jnp.asarray(tokens), jnp.asarray(table))
         self.n_prefills += 1
-        for i, (slot, req) in enumerate(group):
-            # prefill emits the token at position len(prompt) == slot.pos
-            tok = self._sample(last[i], req.rid, self.sched.slots[slot].pos)
-            self.results[req.rid].append(tok)
-            self.sched.record_emit(slot, tok)
+        with obs.span("server.sample"):
+            for i, (slot, req) in enumerate(group):
+                # prefill emits the token at position len(prompt) == slot.pos
+                tok = self._sample(last[i], req.rid,
+                                   self.sched.slots[slot].pos)
+                self.results[req.rid].append(tok)
+                self.sched.record_emit(slot, tok)
 
     def _decode_once(self):
         bucket = self.sched.decode_bucket()
         tokens, pos, table = self.sched.decode_inputs()
         fn = self._decode_fn(bucket)
-        with self.mesh:
+        with obs.span("server.decode"), self.mesh:
             logits, self.pool = fn(self.params, self.pool,
                                    jnp.asarray(tokens), jnp.asarray(table),
                                    jnp.asarray(pos))
         self.n_decodes += 1
-        for slot in self.sched.active_slots():
-            s = self.sched.slots[slot]
-            # the decode wrote position s.pos; its token lands at s.pos + 1
-            tok = self._sample(logits[slot], s.req.rid, s.pos + 1)
-            self.results[s.req.rid].append(tok)
-            if not self.sched.record_emit(slot, tok):
-                self.sched.advance(slot)
+        with obs.span("server.sample"):
+            for slot in self.sched.active_slots():
+                s = self.sched.slots[slot]
+                # the decode wrote position s.pos; its token lands at s.pos + 1
+                tok = self._sample(logits[slot], s.req.rid, s.pos + 1)
+                self.results[s.req.rid].append(tok)
+                if not self.sched.record_emit(slot, tok):
+                    self.sched.advance(slot)
 
     # -- churn ----------------------------------------------------------------
 
@@ -197,6 +208,7 @@ class DecodeServer:
     def stats(self) -> dict:
         out = {"steps": self.n_steps, "prefills": self.n_prefills,
                "decodes": self.n_decodes, "suspends": self.n_suspends,
+               "programs": len(self._prefill_fns) + len(self._decode_fns),
                "evicted": self.sched.n_evicted,
                "queued": len(self.sched.queue),
                "active": len(self.sched.active_slots()),

@@ -274,7 +274,46 @@ def test_decode_under_live_updates_matches_offline_fold(cfg, mesh, pod,
     np.testing.assert_array_equal(
         np.array([srv.results[b] for b in range(B)]), ref)
     assert bridge.stats() == {"messages_folded": 8, "n_folds": 2,
-                              "pending": 0}
+                              "pending": 0, "fold_programs": 2}
+
+
+def test_step_spans_and_program_counts(cfg, mesh, pod, params, prompts,
+                                      tmp_path):
+    """One profiled step names its host phases in order; ``programs`` and
+    ``fold_programs`` count the distinct compiled shapes, not the calls."""
+    scfg = SubCGEConfig(rank=4, refresh_period=2, eps=1e-3)
+    serve = ServeConfig(max_batch=2, page_size=4, n_pages=8, max_seq=CAP)
+    bridge = LiveUpdateBridge(cfg, scfg, 7, node=0)
+    srv = DecodeServer(cfg, params, serve, mesh=mesh, pod=pod, bridge=bridge)
+    keys = []
+    for attr in ("_prefill_fn", "_decode_fn", "_fold_fn"):
+        owner = bridge if attr == "_fold_fn" else srv
+        orig = getattr(owner, attr)
+
+        def record(*key, orig=orig, attr=attr):
+            keys.append((attr, key))
+            return orig(*key)
+        setattr(owner, attr, record)
+    for b in range(B):
+        srv.submit(Request(rid=b, prompt=prompts[b], max_new=NEW))
+    bridge.ingest_arrays(*_msg_batch(7, [0, 0, 1]))
+    with jax.profiler.trace(str(tmp_path)):
+        srv.step()
+    xplane = next(tmp_path.rglob("*.xplane.pb"))
+    events = [ev for plane in jax.profiler.ProfileData.from_file(
+        str(xplane)).planes for line in plane.lines for ev in line.events
+        if ev.name.startswith("server.")]
+    assert [ev.name for ev in sorted(events, key=lambda e: e.start_ns)] \
+        == ["server.fold", "server.admit", "server.prefill", "server.sample",
+            "server.decode", "server.sample"]
+    bridge.ingest_arrays(*_msg_batch(7, [1, 2]))
+    srv.run()
+    calls = {a: [k for b, k in keys if b == a] for a, _ in keys}
+    assert len(calls["_prefill_fn"]) > len(set(calls["_prefill_fn"]))
+    st = srv.stats()
+    assert st["programs"] == len(set(calls["_prefill_fn"])) \
+        + len(set(calls["_decode_fn"]))
+    assert st["bridge"]["fold_programs"] == len(set(calls["_fold_fn"])) == 2
 
 
 def test_bridge_ingest_skips_inbox_padding():

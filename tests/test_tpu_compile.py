@@ -9,6 +9,8 @@ only one process may load the TPU library at a time, so every test-runner
 worker must collect the same tests and only the worker running this file
 may load it.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -43,6 +45,14 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _kernels(hlo):
+    """Names of the kernel ops in a compiled program, numeric suffix
+    dropped: each is its dispatcher's name (``pallas_call(name=...)``),
+    which a device profile shows."""
+    return {m[1] for m in re.finditer(
+        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*tpu_custom_call", hlo)}
+
+
 def _abs(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -64,7 +74,7 @@ def test_rank1_matmul_compiles(one_chip, op, M, wshape):
                                             backend="pallas"),
         _abs((M, K), BF16, one_chip), _abs(wshape, BF16, one_chip),
         _abs((rows,), F32, one_chip), _abs((cols,), F32, one_chip))
-    assert "tpu_custom_call" in hlo
+    assert _kernels(hlo) == {op}
 
 
 def test_rank1_matmul_expert_compiles(one_chip):
@@ -75,7 +85,7 @@ def test_rank1_matmul_expert_compiles(one_chip):
                                                    backend="pallas"),
         _abs((E, C, n), BF16, one_chip), _abs((E, n, m), BF16, one_chip),
         _abs((n, E), F32, one_chip), _abs((m, E), F32, one_chip))
-    assert "tpu_custom_call" in hlo
+    assert _kernels(hlo) == {"rank1_matmul_expert"}
 
 
 @pytest.mark.parametrize("E", [None, 2])
@@ -91,7 +101,8 @@ def test_subcge_apply_compiles(one_chip, E):
     hlo = _compile(fn, _abs((L, n, m), BF16, one_chip),
                    _abs(ushape, F32, one_chip), _abs(ashape, F32, one_chip),
                    _abs(vshape, F32, one_chip))
-    assert "tpu_custom_call" in hlo
+    assert _kernels(hlo) == {"subcge_apply" if E is None
+                             else "subcge_apply_epochs"}
 
 
 @pytest.mark.parametrize("wspec", [P("model", None), P(None, "model")])
