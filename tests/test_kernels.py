@@ -310,6 +310,77 @@ def test_rank1_matmul_expert_kernel(ecnm, dtype):
                                rtol=tol, atol=tol * 20)
 
 
+# the schedule: several output tiles (one a partial edge tile), several k
+# steps and row blocks, forced by the block overrides at 128.  x·u is summed
+# during the first output tile only and reused by the others; vmapped over
+# clients with a shared W (the train step's form) the scratch carries over
+# the client grid axis too, which a stale x·u would show
+@pytest.mark.parametrize("clients", [None, 3])
+@pytest.mark.parametrize("M", [64, 192])
+@pytest.mark.parametrize("op", ["rank1_matmul", "rank1_matmul_t"])
+def test_rank1_schedule_tiles_and_clients(op, M, clients):
+    from repro.kernels import rank1_matmul as r1  # sfcheck: noqa[SF006] -- drives the kernels' block overrides
+    K, N = 384, 416
+    lead = () if clients is None else (clients,)
+    ks = jax.random.split(jax.random.PRNGKey(M + (clients or 0)), 5)
+    x = jax.random.normal(ks[0], lead + (M, K), jnp.bfloat16)
+    wshape = (K, N) if op == "rank1_matmul" else (N, K)
+    W = jax.random.normal(ks[1], wshape, jnp.bfloat16)
+    a = jax.random.normal(ks[2], lead + (K,), jnp.float32)   # contracted
+    b = jax.random.normal(ks[3], lead + (N,), jnp.float32)   # output side
+    s = jax.random.normal(ks[4], lead, jnp.float32)
+    if op == "rank1_matmul":
+        def got_fn(x, W, a, b, s):
+            return r1.rank1_matmul(x, W, a, b, s, bm=128, bn=128, bk=128,
+                                   interpret=True)
+        want_fn = lambda x, W, a, b, s: ref.rank1_matmul(x, W, a, b, s)
+    else:
+        def got_fn(x, W, a, b, s):
+            return r1.rank1_matmul_t(x, W, b, a, s, bm=128, bo=128, bk=128,
+                                     interpret=True)
+        want_fn = lambda x, W, a, b, s: ref.rank1_matmul_t(x, W, b, a, s)
+    if clients is not None:
+        axes = (0, None, 0, 0, 0)
+        got_fn, want_fn = jax.vmap(got_fn, axes), jax.vmap(want_fn, axes)
+    got = got_fn(x, W, a, b, s)
+    want = want_fn(x, W, a, b, s)
+    assert got.shape == lead + (M, N)
+    np.testing.assert_allclose(np.asarray(got, jnp.float32),
+                               np.asarray(want, jnp.float32),
+                               rtol=4e-2, atol=4e-2 * 20)
+
+
+# the block rule at the shapes that run it: the train cell's per-client
+# OPT-1.3B matrices and tied head, Qwen1.5-0.5B's (2816 = 22·128, a 152k
+# head), chip_smoke's 1016 rows and TinyLlama's 32000 head; f32 operands
+# (the simulator's models) must fit the same budget
+OPT_SHAPES = [(512, 2048, 2048), (512, 2048, 8192), (512, 8192, 2048),
+              (512, 2048, 50272)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("mkn", OPT_SHAPES + [
+    (256, 1024, 1024), (256, 1024, 2816), (256, 2816, 1024),
+    (256, 1024, 151936), (1016, 2048, 8192), (1016, 2048, 32000),
+    (1016, 2048, 50272), (64, 384, 416), (7, 96, 10)])
+def test_rank1_blocks(mkn, dtype):
+    from repro.kernels import rank1_matmul as r1  # sfcheck: noqa[SF006] -- the kernels' block rule
+    M, K, N = mkn
+    bm, bn, bk = r1.rank1_blocks(M, K, N, dtype, dtype)
+    for blk, dim in ((bm, M), (bn, N), (bk, K)):
+        assert blk == dim or (blk % 128 == 0 and blk < dim), (blk, dim)
+    assert K % bk == 0
+    assert r1.vmem_bytes(bm, bn, bk, dtype, dtype) <= r1.VMEM_BUDGET
+    if dtype == jnp.bfloat16 and mkn in OPT_SHAPES:
+        # whole rows of a client, wide and deep tiles: compute-bound steps
+        assert (bm, bn, bk) == (512, 2048, 2048)
+        assert bm * bn / (bm + bn) >= 240
+    if dtype == jnp.bfloat16 and mkn == (256, 1024, 2816):
+        assert bn == 1408          # 2816 = 2 x 1408, no edge tile
+    if mkn == (256, 1024, 151936):
+        assert bn >= 1024          # an edge tile, not the 128 divisor
+
+
 def test_rank1_kernels_accept_traced_scale():
     # the dual forward flips s = ±ε under jit — s must be traceable
     ks = jax.random.split(jax.random.PRNGKey(6), 4)

@@ -77,6 +77,33 @@ def test_rank1_matmul_compiles(one_chip, op, M, wshape):
     assert _kernels(hlo) == {op}
 
 
+@pytest.mark.parametrize("op,M,wshape", [
+    # the train cell's form: 16 clients vmapped over one shared W, 512 rows
+    # each (2 x 256 tokens); opt-1.3b's q/k/v/o, fc1, fc2 and tied head
+    ("rank1_matmul", 512, (2048, 2048)),
+    ("rank1_matmul", 512, (2048, 8192)),
+    ("rank1_matmul", 512, (8192, 2048)),
+    ("rank1_matmul_t", 512, (50272, 2048)),
+    # qwen1.5-0.5b's fc2: 2816 = 22 x 128 contracted, 256 rows per client
+    ("rank1_matmul", 256, (2816, 1024)),
+])
+def test_rank1_matmul_compiles_vmapped(one_chip, op, M, wshape):
+    """Default blocks (``rank1_blocks``) under the step's vmap: the VMEM
+    they take passes the compiler, and the op keeps its name."""
+    C = 16
+    transposed = op == "rank1_matmul_t"
+    K = wshape[1] if transposed else wshape[0]
+    rows, cols = wshape
+    hlo = _compile(
+        jax.vmap(lambda x, W, u, v, s: getattr(ops, op)(x, W, u, v, s,
+                                                        backend="pallas"),
+                 in_axes=(0, None, 0, 0, 0)),
+        _abs((C, M, K), BF16, one_chip), _abs(wshape, BF16, one_chip),
+        _abs((C, rows), F32, one_chip), _abs((C, cols), F32, one_chip),
+        _abs((C,), F32, one_chip))
+    assert _kernels(hlo) == {op}
+
+
 def test_rank1_matmul_expert_compiles(one_chip):
     # kimi-k2 expert widths (d_model 7168, expert ff 2048), 4 experts held
     E, C, n, m = 4, 1016, 7168, 2048
