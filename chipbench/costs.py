@@ -1,10 +1,15 @@
-"""Operations and bytes the benchmark's work requires, from shapes alone.
+"""Operations and bytes the ``decoder`` family's work requires, from shapes
+alone.
 
 The arithmetic follows the program's analytic cost model (GEMMs at 2·m·k·n,
 causal attention over half the context for train and prefill, the
 SubCGE fold as one read and one write of each weight), restated here for the
-dense decoders of ``configs/`` so that no change to the program moves the
-yardstick.  ``m`` is a configuration's ``model`` block.
+dense decoders (``"reference": "decoder"``) so that no change to the
+program moves the yardstick.  A train cell reaches it through
+``references/decoder.train_cost``; the serve window calls it directly, as
+only dense decoders are served.  Another family's reference module brings
+its own count.  ``m`` is a configuration's ``model`` block without
+``layers``.
 """
 from __future__ import annotations
 

@@ -13,6 +13,11 @@ program's tree layout (one scanned group ``g0/s0`` of stacked layers, plus
 * ReLU MLP (OPT) or SwiGLU ``silu(x W1) · (x W3) W2`` (Qwen);
 * tied output head, next-token cross entropy.
 
+A configuration names this module with ``"reference": "decoder"``; the
+windows reach it as ``ctx.ref``.  Besides the equations it gives the
+family's count of operations and bytes of a train cell (``train_cost``,
+from ``costs.py``).
+
 ``prec`` selects the arithmetic: ``"f32"`` is the reference; ``"bf16"`` and
 ``"fp8"`` (e4m3, one absmax scale per tensor) round every matmul operand
 first — the lower-precision controls.
@@ -30,7 +35,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from chipbench import rng
+from chipbench import costs, rng
 from chipbench.weights import flat_paths, is_vector
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -322,3 +327,21 @@ def zo_step(m, params, tokens, step, base_seed, hp, prec="f32"):
     new = apply_messages(params, seeds, coefs, steps, refresh[None],
                          hp["rank"], hp["tau"], base_seed)
     return new, jnp.mean(losses), alphas
+
+
+# ---------------------------------------------------------------------------
+# operations and bytes
+# ---------------------------------------------------------------------------
+
+def train_cost(m, wl) -> dict:
+    """What one SeedFlood step of the train cell ``wl`` requires: the whole
+    step's model FLOPs (``step_flops``), (kernel, flops, bytes) of each
+    rank-1 call site over the clients (``rank1``), the perturbed forwards per
+    step that run them (``rank1_per_step``) and the fold's (flops, bytes)
+    (``subcge_apply``)."""
+    n, b, T = wl["clients"], wl["seqs_per_client"], wl["seq_len"]
+    calls = costs.rank1_calls(m, b * T)
+    return {"step_flops": costs.train_step_flops(m, n, b, T, wl["rank"]),
+            "rank1": [(c["kernel"], *costs.rank1_cost(c, n)) for c in calls],
+            "rank1_per_step": 2,                 # the ± forwards
+            "subcge_apply": costs.subcge_apply_cost(m, wl["rank"])}

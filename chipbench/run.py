@@ -6,14 +6,18 @@
 A cell is an entry of BENCHMARK.json's ``workloads``: a configuration
 (``chipbench/configs/<config>.json``) under a traffic mix
 (``chipbench/workloads/<traffic>.json``), whose ``kind`` names the window
-that drives it (``chipbench/windows/<kind>.py``).  The run builds the program's entry
-points and the inputs from ``--seed``, warms every shape the cell uses (the
-set-up, ``setup_s``), measures for ``--seconds``, reads the device's peak
-memory, frees the program's state, and decides ``correct`` by comparing
-what the timed path produced with the plain reference
-(``chipbench/references/``).  With ``--trace 1`` the window runs under the
-profiler and the line carries the per-layer metrics that
-``chipbench/metrics/<metric>.py`` read from the trace.
+that drives it (``chipbench/windows/<kind>.py``).  The configuration names
+the program's architecture, which must be the one its ``model`` block
+states layer by layer, and its plain reference
+(``chipbench/references/<reference>.py``), which owns its family's
+equations and its count of operations and bytes.  The run builds the
+program's entry points and the inputs from ``--seed``, warms every shape
+the cell uses (the set-up, ``setup_s``), measures for ``--seconds``, reads
+the device's peak memory, frees the program's state, and decides
+``correct`` by comparing what the timed path produced with that reference.
+With ``--trace 1`` the window runs under the profiler and the line carries
+the per-layer metrics that ``chipbench/metrics/<metric>.py`` read from the
+trace.
 
 The last line of standard output is one JSON object; the numbers compared
 for ``correct`` close standard error and the line (key ``check``).  Without
@@ -84,8 +88,9 @@ def cell_metrics(bench: dict, cell: str, kind: str) -> list[dict]:
 
 @dataclasses.dataclass
 class Ctx:
-    """What a window gets: the cell, its configuration, the seed and the
-    program's architecture object (checked against the configuration)."""
+    """What a window gets: the cell, its configuration, the seed, the
+    program's architecture object (checked against the configuration) and
+    the configuration's reference module."""
     name: str
     workload: dict
     config: dict
@@ -94,6 +99,7 @@ class Ctx:
     arch: object
     peak: dict
     chips: int
+    ref: object = None
 
     @property
     def model(self) -> dict:
@@ -105,41 +111,123 @@ class Ctx:
         return jnp.dtype(self.config["dtype"])
 
 
-MODEL_KEYS_FROM_ARCH = {
-    "d_model": lambda a: a.d_model,
-    "vocab": lambda a: a.vocab,
-    "n_layers": lambda a: sum(g.reps * len(g.slots) for g in a.groups),
-    "n_heads": lambda a: a.groups[0].slots[0].attn.n_heads,
-    "n_kv_heads": lambda a: a.groups[0].slots[0].attn.n_kv_heads,
-    "head_dim": lambda a: a.groups[0].slots[0].attn.head_dim,
-    "d_ff": lambda a: a.groups[0].slots[0].d_ff,
-    "qkv_bias": lambda a: a.groups[0].slots[0].attn.qkv_bias,
-    "norm": lambda a: a.norm,
-    "pos": lambda a: a.pos,
-    "act": lambda a: a.act,
-    "gated_mlp": lambda a: a.gated_mlp,
-    "tie_embeddings": lambda a: a.tie_embeddings,
-}
+#: what a ``model`` block states of the whole architecture
+ARCH_KEYS = ("d_model", "vocab", "norm", "pos", "act", "gated_mlp",
+             "tie_embeddings")
+#: what a run of ``layers`` states of a layer, by the part that has it
+ATTN_FIELDS = ("n_heads", "n_kv_heads", "head_dim", "qkv_bias", "window",
+               "q_lora", "kv_lora", "rope_head_dim", "v_head_dim")
+MAMBA_FIELDS = ("d_inner", "d_state", "d_conv", "dt_rank")
+MOE_FIELDS = ("n_experts", "top_k", "d_ff_expert", "n_shared")
+LAYER_FIELDS = ("mixer", *ATTN_FIELDS, *MAMBA_FIELDS, "ffn", "d_ff",
+                *MOE_FIELDS)
+#: the keys of a block without ``layers`` that state its one dense layer
+DENSE_KEYS = ("n_heads", "n_kv_heads", "head_dim", "qkv_bias", "d_ff")
+
+
+def layer_fields(slot) -> dict:
+    """What a configuration states of one of the program's layers (a
+    ``LayerCfg``): its mixer and ffn, and the fields of the parts it runs."""
+    out = {"mixer": slot.mixer}
+    if slot.mixer == "attn":
+        out.update((k, getattr(slot.attn, k)) for k in ATTN_FIELDS)
+    elif slot.mixer == "mamba":
+        out.update((k, getattr(slot.mamba, k)) for k in MAMBA_FIELDS)
+    out["ffn"] = slot.ffn
+    if slot.ffn == "dense":
+        out["d_ff"] = slot.d_ff
+    elif slot.ffn == "moe":
+        out.update((k, getattr(slot.moe, k)) for k in MOE_FIELDS)
+    return out
+
+
+def program_runs(arch) -> list[tuple[int, dict]]:
+    """``arch.layer_cfgs()`` run-length encoded: (count, layer fields)."""
+    runs: list[tuple[int, dict]] = []
+    for slot in arch.layer_cfgs():
+        f = layer_fields(slot)
+        if runs and runs[-1][1] == f:
+            runs[-1] = (runs[-1][0] + 1, f)
+        else:
+            runs.append((1, f))
+    return runs
+
+
+def stated_runs(name: str, m: dict) -> list[dict]:
+    """The runs of layers a ``model`` block states.  A block without
+    ``layers`` states ``n_layers`` dense layers of global attention."""
+    if "layers" not in m:
+        return [{"count": m["n_layers"], "mixer": "attn",
+                 "n_heads": m["n_heads"], "n_kv_heads": m["n_kv_heads"],
+                 "head_dim": m["head_dim"], "qkv_bias": m["qkv_bias"],
+                 "window": None, "q_lora": 0, "kv_lora": 0,
+                 "rope_head_dim": 0, "v_head_dim": 0, "ffn": "dense",
+                 "d_ff": m["d_ff"]}]
+    stray = [k for k in DENSE_KEYS if k in m]
+    if stray:
+        raise BenchError(f"{name}: a model block with layers states "
+                         f"{', '.join(stray)} in its runs, not beside them")
+    return m["layers"]
+
+
+def check_layers(name: str, stated: list[dict], runs: list) -> None:
+    """Refuse unless the stated runs are the program's, run by run, naming
+    the first layer and field that differ."""
+    start = 0
+    for r, want in enumerate(stated):
+        count, have = runs[r] if r < len(runs) else (0, {})
+        where = f"{name}: layer {start} (run {r})"
+        if have:
+            keys = [k for k in LAYER_FIELDS if k in have or k in want]
+            keys += sorted(set(want) - set(LAYER_FIELDS) - {"count"})
+            for k in keys:
+                if have.get(k, "(none)") != want.get(k, "(none)"):
+                    raise BenchError(
+                        f"{where}: program has {k}={have.get(k, '(none)')!r}"
+                        f", configuration states {want.get(k, '(none)')!r}")
+        if want.get("count") != count:
+            raise BenchError(f"{where}: program has count={count!r}, "
+                             f"configuration states {want.get('count')!r}")
+        start += count
+    if len(runs) > len(stated):
+        raise BenchError(f"{name}: layer {start} (run {len(stated)}): "
+                         f"program has count={runs[len(stated)][0]!r}, "
+                         f"configuration states none")
 
 
 def program_arch(config: dict):
     """The program's architecture for ``config["arch"]``, refused unless it
-    is the one the configuration's ``model`` block states (one group of one
-    dense attention slot, every size equal)."""
+    is the one the configuration's ``model`` block states: the whole-model
+    keys, and every layer, as runs of equal layers (``layers``, or one run
+    of dense layers for a block without it)."""
     from repro.configs import archs
-    arch = archs.get(config["arch"])
+    name = config["arch"]
+    arch = archs.get(name)
     if config.get("arch_reduced"):
         arch = archs.reduced(arch, **config["arch_reduced"])
-    if len(arch.groups) != 1 or len(arch.groups[0].slots) != 1:
-        raise BenchError(f"{config['arch']}: not one scanned dense group")
     m = config["model"]
-    for key, get in MODEL_KEYS_FROM_ARCH.items():
-        if get(arch) != m[key]:
-            raise BenchError(f"{config['arch']}: program has {key}="
-                             f"{get(arch)!r}, configuration states {m[key]!r}")
+    for key in ARCH_KEYS + (("n_layers",) if "n_layers" in m else ()):
+        if getattr(arch, key) != m[key]:
+            raise BenchError(f"{name}: program has {key}="
+                             f"{getattr(arch, key)!r}, configuration states "
+                             f"{m[key]!r}")
     if m["pos"] == "rope" and float(arch.rope_theta) != float(m["rope_theta"]):
-        raise BenchError(f"{config['arch']}: rope_theta differs")
+        raise BenchError(f"{name}: rope_theta differs")
+    check_layers(name, stated_runs(name, m), program_runs(arch))
     return arch
+
+
+def load_reference(config: dict):
+    """The module ``chipbench/references/<config["reference"]>.py``: the
+    configuration's family's equations (``zo_step``, ``forward``,
+    ``apply_messages``) and its count of operations and bytes
+    (``train_cost``)."""
+    name = config.get("reference")
+    if not (isinstance(name, str) and name.isidentifier() and os.path.exists(
+            os.path.join(HERE, "references", name + ".py"))):
+        raise BenchError(f"{config.get('name')}: no reference "
+                         f"chipbench/references/{name}.py")
+    return importlib.import_module("chipbench.references." + name)
 
 
 def find_device(chips: int, require_chip: bool):
@@ -222,6 +310,7 @@ def make_ctx(cell: str, seed: int, seconds: float, *,
           **overrides.get("workload", {}), "chips": entry["chips"]}
     conf = {**load_json("configs", entry["config"] + ".json"),
             **overrides.get("config", {})}
+    ref = load_reference(conf)
     try:
         import repro  # noqa: F401
     except ImportError as e:
@@ -235,7 +324,7 @@ def make_ctx(cell: str, seed: int, seconds: float, *,
     log(f"# {cell}: {dev.platform} {dev.device_kind!r} x{len(devices)}, "
         f"compile cache {cache_dir}", file=sys.stderr)
     ctx = Ctx(cell, wl, conf, int(seed), float(seconds),
-              program_arch(conf), peak, wl["chips"])
+              program_arch(conf), peak, wl["chips"], ref)
     return ctx, window, devices, bench
 
 

@@ -8,6 +8,16 @@ RED_OPT = {"d_model": 64, "n_layers": 1, "n_heads": 4, "n_kv_heads": 4,
 RED_QWEN = {**RED_OPT, "norm": "rmsnorm", "norm_eps": 1e-6, "pos": "rope",
             "pos_table": 0, "rope_theta": 1e6, "act": "silu",
             "gated_mlp": True}
+#: RED_OPT with its layer stated as a run of ``layers``, as a configuration
+#: that is not one scanned dense group states them; test_discovery's new
+#: configuration runs it under a reference module of its own
+RED_OPT_LAYERS = {
+    **{k: v for k, v in RED_OPT.items()
+       if k not in ("n_heads", "n_kv_heads", "head_dim", "qkv_bias", "d_ff")},
+    "layers": [{"count": 1, "mixer": "attn", "n_heads": 4, "n_kv_heads": 4,
+                "head_dim": 16, "qkv_bias": True, "window": None,
+                "q_lora": 0, "kv_lora": 0, "rope_head_dim": 0,
+                "v_head_dim": 0, "ffn": "dense", "d_ff": 128}]}
 
 #: limits for float32 runs at these widths, where program and reference
 #: agree to rounding (~1e-5)

@@ -45,3 +45,28 @@ def test_roofline_takes_the_larger_bound():
     peak = {"bf16_flops": 100.0, "hbm_bytes_per_s": 10.0}
     assert costs.least_seconds(1000.0, 50.0, peak) == 10.0
     assert costs.least_seconds(100.0, 50.0, peak) == 5.0
+
+
+def test_decoder_train_cost_is_the_count_the_train_window_used():
+    """The train cell's count, now taken from its reference module, is
+    the dict the window built from costs.py before: same keys, same
+    numbers to the last bit, at 16 clients × 2 × 256 tokens, rank 16."""
+    from chipbench.references import decoder
+    wl = {"clients": 16, "seqs_per_client": 2, "seq_len": 256, "rank": 16}
+    got = decoder.train_cost(OPT, wl)
+    n, b, T = 16, 2, 256
+    calls = costs.rank1_calls(OPT, b * T)
+    assert got == {"step_flops": costs.train_step_flops(OPT, n, b, T, 16),
+                   "rank1": [(c["kernel"], *costs.rank1_cost(c, n))
+                             for c in calls],
+                   "rank1_per_step": 2,
+                   "subcge_apply": costs.subcge_apply_cost(OPT, 16)}
+    layer = [("rank1_matmul", 1650878054400.0, 1818230784.0)] * 4 \
+        + [("rank1_matmul", 6601096298496.0, 4847566848.0)] * 2
+    assert got == {"step_flops": 43442929123328.0,
+                   "rank1": layer + [("rank1_matmul_t", 1687705616384.0,
+                                      1066473472.0)],
+                   "rank1_per_step": 2,
+                   "subcge_apply": (43791400960.0, 5283471360.0)}
+    assert list(got) == ["step_flops", "rank1", "rank1_per_step",
+                         "subcge_apply"]

@@ -1,6 +1,7 @@
-"""A configuration, a traffic mix and a per-layer metric added as new files
-(and entries of BENCHMARK.json) are found with no edit to an existing file;
-a checkout without the program, or a host without a TPU, gets no result."""
+"""A configuration, its reference module, a traffic mix and a per-layer
+metric added as new files (and entries of BENCHMARK.json) are found with no
+edit to an existing file; a checkout without the program, or a host without
+a TPU, gets no result."""
 import json
 import os
 import shutil
@@ -19,6 +20,31 @@ def read(m):
     return float(m["rec"]["steps"])
 '''
 
+NEW_REFERENCE = '''"""The dense decoder's equations and count for a model block that states
+its one layer as a run of ``layers`` (a new family, found by the
+configuration's ``reference``)."""
+from chipbench.references import decoder
+
+calls = []
+
+
+def dense(m):
+    (run,) = m["layers"]
+    return {**m, "n_layers": run["count"],
+            **{k: run[k] for k in ("n_heads", "n_kv_heads", "head_dim",
+                                   "qkv_bias", "d_ff")}}
+
+
+def zo_step(m, *args, **kw):
+    calls.append("zo_step")
+    return decoder.zo_step(dense(m), *args, **kw)
+
+
+def train_cost(m, wl):
+    calls.append("train_cost")
+    return {**decoder.train_cost(dense(m), wl), "layer_runs": len(m["layers"])}
+'''
+
 DRIVE = '''
 import json, sys
 sys.path.insert(0, {root!r})
@@ -28,8 +54,9 @@ bench = run.benchmark_spec()
 names = [e["name"] for e in run.cell_metrics(bench, "train.tiny.c2",
                                              "per_layer")]
 read = run.load_metric("steps_seen.train")
+ref = sys.modules["chipbench.references.layered"]
 print(json.dumps({{"correct": r["correct"], "metrics": sorted(r["metrics"]),
-                  "per_layer": names,
+                  "per_layer": names, "reference_calls": sorted(set(ref.calls)),
                   "steps": read({{"rec": {{"steps": 3}}}})}}))
 '''
 
@@ -45,9 +72,10 @@ def test_new_files_are_found(tmp_path):
     cb = tmp_path / "chipbench"
     conf = json.load(open(cb / "configs" / "opt-1.3b.json"))
     conf.update(name="opt-tiny", arch="opt-125m",
-                arch_reduced={"d_model": 64}, model=cells.RED_OPT,
-                dtype="float32")
+                arch_reduced={"d_model": 64}, model=cells.RED_OPT_LAYERS,
+                dtype="float32", reference="layered")
     (cb / "configs" / "opt-tiny.json").write_text(json.dumps(conf))
+    (cb / "references" / "layered.py").write_text(NEW_REFERENCE)
     wl = json.load(open(cb / "workloads" / "train.opt-1.3b.c16.json"))
     wl.update(cells.overrides("train.opt-1.3b.c16", "jnp")["workload"])
     (cb / "workloads" / "train.tiny.c2.json").write_text(json.dumps(wl))
@@ -77,6 +105,7 @@ def test_new_files_are_found(tmp_path):
     assert got["metrics"] == ["setup_s", "train_tokens_per_s"]
     assert "steps_seen.train" in got["per_layer"]
     assert got["steps"] == 3.0
+    assert got["reference_calls"] == ["train_cost", "zo_step"]
 
 
 def run_cli(cwd, env):

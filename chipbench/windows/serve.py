@@ -16,7 +16,9 @@ runs the first step: every prefill shape and the decode bucket compile
 there.  Sender steps cross a τ boundary half-way through the window, so
 folds under two subspaces occur.
 
-``correct`` replays the served sessions in the reference: a sample drawn
+``correct`` replays the served sessions in the configuration's reference
+(``ctx.ref``; the dense cost of a step stays ``costs.py``'s, as only dense
+decoders are served): a sample drawn
 from the seed, one session of each prompt length, is prefilled and decoded
 in float32 along its served tokens with the weights as each step had them
 (every fold reapplied by the reference), and the widest gap by which a
@@ -266,8 +268,7 @@ def fold_arrays(ctx, readings: dict, rounds: list[int]) -> tuple:
 def fold_fn(ctx, readings: dict, prec: str = "f32"):
     """jit(params, seeds, coefs, steps, epochs -> params): the reference's
     fold of one step's messages."""
-    from chipbench.references import decoder as ref
-    wl = ctx.workload
+    wl, ref = ctx.workload, ctx.ref
     return jax.jit(lambda params, seeds, coefs, steps, epochs:
                    ref.apply_messages(params, seeds, coefs, steps, epochs,
                                       wl["rank"], wl["tau"],
@@ -289,9 +290,7 @@ def replay(ctx, readings: dict, precs=("f32",)):
     """Replay the sampled sessions in the reference, step by step, with
     every fold reapplied.  Yields per step the reference logits for each
     precision in ``precs`` beside the served next tokens."""
-    from chipbench.references import decoder as ref
-
-    m, wl = ctx.model, ctx.workload
+    m, wl, ref = ctx.model, ctx.workload, ctx.ref
     sess = readings["sessions"]
     rids = sample_sessions(ctx, sess)
     B, S = len(rids), wl["max_seq"]

@@ -9,10 +9,12 @@ after the last.  The window continues the same object from there: each step
 is one call of the compiled step on the next batch of the feed, ending in
 the host reading the loss, as ``launch/train.train`` does.
 
-``correct`` compares those first steps with the reference's
-(``references/decoder.zo_step`` in float32): the loss of each step, and by
-the worst leaf the norm of the first update and of the change after the
-last step.
+``correct`` compares those first steps with the reference's (the
+configuration's reference module, ``ctx.ref``, whose ``zo_step`` runs in
+float32): the loss of each step, and by the worst leaf the norm of the first
+update and of the change after the last step.  The same module counts the
+step's operations and bytes (``train_cost``), which the per-layer metrics
+divide by the device's times.
 """
 from __future__ import annotations
 
@@ -22,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import compare, costs, rng, weights
+from chipbench import compare, rng, weights
 
 FEED_SALT = 1
 
@@ -127,14 +129,8 @@ def end_to_end(st: State, ctx, rec) -> dict:
 
 
 def cost(st: State, ctx, rec) -> dict:
-    wl, m = ctx.workload, ctx.model
-    n, b, T = wl["clients"], wl["seqs_per_client"], wl["seq_len"]
-    calls = costs.rank1_calls(m, b * T)
     return {"steps": rec["steps"],
-            "step_flops": costs.train_step_flops(m, n, b, T, wl["rank"]),
-            "rank1": [(c["kernel"], *costs.rank1_cost(c, n)) for c in calls],
-            "rank1_per_step": 2,                 # the ± forwards
-            "subcge_apply": costs.subcge_apply_cost(m, wl["rank"])}
+            **ctx.ref.train_cost(ctx.model, ctx.workload)}
 
 
 def finish(st: State, ctx, rec) -> None:
@@ -146,9 +142,7 @@ def reference_readings(ctx, prec: str = "f32", fault=None) -> dict:
     """The reference's first steps from the same seed: losses and per-leaf
     norms of the change after the first and the last step.  ``prec`` below
     f32 or a ``fault`` give the controls that calibrate the limits."""
-    from chipbench.references import decoder as ref
-
-    wl, m = ctx.workload, ctx.model
+    wl, m, ref = ctx.workload, ctx.model, ctx.ref
     hp = {**hparams(wl), "fault": fault}
     step = jax.jit(lambda p, tok, t, bs: ref.zo_step(m, p, tok, t, bs, hp,
                                                      prec))
